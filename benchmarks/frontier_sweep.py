@@ -11,10 +11,10 @@ per-hop pallas_call chain at the same W.
 The perf claim being tracked (§Perf hillclimb): W>1 trades a modest increase
 in distance computations for a W-fold cut in rounds — the round count is the
 serial depth of the search, which is what the accelerator latency follows —
-at equal recall.  On this CPU container the fused/persistent paths run
-through the Pallas *interpreter*, so their absolute us/query measures
-emulation, not TPU silicon; the unfused W-sweep timings and the hop/dist
-counters are load-bearing everywhere.
+at equal recall.  On a CPU backend the fused/persistent paths run through
+the Pallas *interpreter* (kernels/backend.resolve_interpret), so their
+absolute us/query measures emulation, not TPU silicon; on a TPU they are
+compiled.  The hop/dist counters are load-bearing everywhere.
 
   PYTHONPATH=src python -m benchmarks.run --only frontier_sweep
 """
@@ -57,8 +57,7 @@ def run(n: int = None):
     for fused in (False, True):
         for W in WIDTHS:
             spec = T.TraversalSpec(ef=EF, visited_mode="bloom",
-                                   frontier_width=W, use_pallas=fused,
-                                   pallas_interpret=True)
+                                   frontier_width=W, use_pallas=fused)
             fn = _search_fn(spec, n_nodes)
             dt, out = timed(lambda: jax.block_until_ready(
                 fn(q, nbrs, vecs, entries)))
@@ -74,8 +73,7 @@ def run(n: int = None):
     # persistent whole-search kernel vs the per-hop pallas_call chain
     for W in (1, 4):
         spec = T.TraversalSpec(ef=EF, visited_mode="bloom", frontier_width=W,
-                               use_pallas=True, pallas_interpret=True,
-                               use_persistent=True)
+                               use_pallas=True, use_persistent=True)
         fn = _search_fn(spec, n_nodes)
         dt, out = timed(lambda: jax.block_until_ready(
             fn(q, nbrs, vecs, entries)))

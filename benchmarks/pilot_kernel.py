@@ -4,10 +4,9 @@ Runs a fixed number of pilot-stage expansion rounds over the subgraph +
 SVD-primary vectors — once with the op-by-op jnp hop body and once with the
 fused Pallas kernel (kernels/traversal_kernel.py) — and reports hops/s.
 
-On this CPU container the fused path runs through the Pallas *interpreter*,
-so its absolute numbers measure emulation, not TPU silicon; the benchmark's
-job here is (a) an end-to-end exercise of the fused path under jit and
-(b) the harness that reports real speedups on TPU (interpret=False).
+On a CPU backend the fused path runs through the Pallas *interpreter*
+(kernels/backend.resolve_interpret), so its absolute numbers measure
+emulation, not TPU silicon; on a TPU the kernel is compiled.
 
   PYTHONPATH=src python -m benchmarks.run --only pilot_kernel
 """
@@ -49,7 +48,7 @@ def run(n: int = None, B: int = 64, ef: int = 64):
     for name, spec in [
         ("unfused", T.TraversalSpec(ef=ef, visited_mode="bloom")),
         ("fused", T.TraversalSpec(ef=ef, visited_mode="bloom",
-                                  use_pallas=True, pallas_interpret=True)),
+                                  use_pallas=True)),
     ]:
         fn = _stage1_fn(spec, n_nodes)
         dt, out = timed(lambda: jax.block_until_ready(

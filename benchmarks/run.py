@@ -102,6 +102,9 @@ def main(argv=None) -> int:
         os.makedirs(args.json, exist_ok=True)
 
     import importlib
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     failures = []
     for name in names:
         mod = importlib.import_module(f"benchmarks.{name}")

@@ -383,7 +383,8 @@ def make_shardwise_fns(mesh, corpus_axes, query_spec, N: int, R: int):
             vf = v.astype(jnp.float32)
             qn = jnp.sum(qf * qf, axis=-1)[:, None]
             vn = jnp.sum(vf * vf, axis=-1)
-            dot = jnp.einsum("bd,bed->be", qf, vf)
+            dot = jnp.einsum("bd,bed->be", qf, vf,
+                             precision=jax.lax.Precision.HIGHEST)
             d = jnp.maximum(qn + vn - 2.0 * dot, 0.0)
             d = jnp.where(owned, d, 0.0)
             return jax.lax.psum(d, caxes)                      # (B, E) scalars

@@ -70,15 +70,22 @@ class PilotANNIndex:
         self.cfg = cfg
         self.n, self.d = vectors.shape
         n, d = self.n, self.d
+        # wall seconds of each build step (the graph builds add their
+        # knn/prune/reverse/connect steps under full_graph./pilot_graph.)
+        self.build_seconds: Dict[str, float] = {}
+        clock = graph_build.PhaseTimer(self.build_seconds)
 
         # --- SVD rotation & split (§4.1) ---
         self.reducer = svd.svd_fit(vectors, cfg.svd_ratio, seed=cfg.seed)
         rot = self.reducer.rotate(vectors)                     # (n, d)
         dp = self.reducer.d_primary
+        clock.lap("svd")
 
         # --- full graph ---
         self.full_graph = graph_build.build_graph(
-            rot, cfg.R, method=cfg.build_method, seed=cfg.seed)
+            rot, cfg.R, method=cfg.build_method, seed=cfg.seed,
+            clock=graph_build.PhaseTimer(self.build_seconds, "full_graph."))
+        clock.lap("full_graph")
 
         # --- sampled subgraph, rebuilt with the same construction algo ---
         keep = csr.subgraph_sample(self.full_graph, cfg.sample_ratio,
@@ -87,7 +94,9 @@ class PilotANNIndex:
         nk = len(keep_ids)
         if nk > 2:
             sub_compact = graph_build.build_graph(
-                rot[keep_ids], cfg.R, method=cfg.build_method, seed=cfg.seed + 1)
+                rot[keep_ids], cfg.R, method=cfg.build_method,
+                seed=cfg.seed + 1, clock=graph_build.PhaseTimer(
+                    self.build_seconds, "pilot_graph."))
             # remap compacted ids -> original ids; zero-out-degree CSR (§4.3)
             nb = sub_compact.neighbors
             remapped = np.where(nb < len(keep_ids),
@@ -100,6 +109,7 @@ class PilotANNIndex:
         self.keep = keep
         self.keep_ids = keep_ids
         self.n_pilot = nk
+        clock.lap("pilot_graph")
 
         # --- compact pilot id space (DESIGN.md §4): full id -> pilot id
         # (dropped nodes and the full sentinel map to the pilot sentinel nk)
@@ -126,6 +136,7 @@ class PilotANNIndex:
             rot[:, :dp], keep_ids, r=cfg.fes_clusters, n_entry=cfg.n_entry,
             seed=cfg.seed,
             max_capacity=fes.fes_capacity_cap(ne, cfg.fes_clusters))
+        clock.lap("fes")
 
         # --- coarse entry layer (HNSW-hierarchy analogue for the baseline
         #     and the "- FES" ablation: greedy descent over a small sampled
@@ -138,6 +149,7 @@ class PilotANNIndex:
                                                seed=cfg.seed + 7)
         self.coarse_ids = coarse_ids
         self.coarse_graph = coarse_graph
+        clock.lap("coarse")
 
         # --- device arrays ---
         zrow = lambda a: np.concatenate([a, np.zeros((1, a.shape[1]), a.dtype)], 0)
@@ -166,6 +178,8 @@ class PilotANNIndex:
                 np.array([graph_build.medoid(rot[coarse_ids])], np.int32)),
         }
         self.arrays.update(self._quantized_pilot_arrays(cfg.pilot_dtype))
+        jax.block_until_ready(self.arrays)
+        clock.lap("device_put")
         # jit cache keyed on (bucket, params, baseline): client batches are
         # padded to a small fixed ladder of sizes (multistage.pad_to_bucket),
         # so ragged traffic compiles at most len(buckets) executables per

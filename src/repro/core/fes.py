@@ -151,11 +151,13 @@ def fes_select_bruteforce(queries: jax.Array, entries: jax.Array,
 def _xdist(a: jax.Array, b: jax.Array) -> jax.Array:
     an = jnp.sum(a * a, axis=-1)[:, None]
     bn = jnp.sum(b * b, axis=-1)[None, :]
-    return jnp.maximum(an + bn - 2.0 * (a @ b.T), 0.0)
+    dot = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(an + bn - 2.0 * dot, 0.0)
 
 
 def _rowdist(q: jax.Array, ev: jax.Array) -> jax.Array:
     qn = jnp.sum(q * q, axis=-1)[:, None]
     en = jnp.sum(ev * ev, axis=-1)
-    dot = jnp.einsum("bd,bcd->bc", q, ev)
+    dot = jnp.einsum("bd,bcd->bc", q, ev,
+                     precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qn + en - 2.0 * dot, 0.0)

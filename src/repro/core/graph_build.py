@@ -21,7 +21,8 @@ occlusion predicate) are pinned by tests/test_graph_build_props.py.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import time
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -326,67 +327,114 @@ def bfs_reachable(neighbors: np.ndarray, n: int, entry: int) -> np.ndarray:
     return reached
 
 
+def _label_components(nb: np.ndarray, n: int, entry: int
+                      ) -> Tuple[np.ndarray, int]:
+    """Component labels along out-edges: 0 is everything reachable from
+    ``entry``; each later seed (in id order) labels what it reaches among
+    the still-unlabelled nodes."""
+    comp = np.full(n, -1, np.int64)
+    n_comp = 0
+    for seed_node in np.concatenate([[entry], np.arange(n)]):
+        if comp[seed_node] >= 0:
+            continue
+        frontier = np.array([seed_node])
+        comp[seed_node] = n_comp
+        while len(frontier):
+            nxt = nb[frontier].reshape(-1)
+            nxt = nxt[nxt < n]
+            nxt = np.unique(nxt)
+            nxt = nxt[comp[nxt] < 0]
+            comp[nxt] = n_comp
+            frontier = nxt
+        n_comp += 1
+    return comp, n_comp
+
+
+LINK_BLOCK = 4096   # non-core rows scored against the core sample per matmul
+
+
 def connect_components(neighbors: np.ndarray, x: np.ndarray, entry: int,
                        *, sample: int = 2048, seed: int = 0) -> np.ndarray:
-    """NSG-style spanning repair: label weakly-connected components in one
-    sweep, then link every non-core component to the entry component through
-    its (approximately) nearest cross pair, so greedy search from the entry
-    can reach the whole graph."""
+    """NSG-style spanning repair, so greedy search from ``entry`` reaches
+    every row.  Components are labelled along out-edges in one sweep
+    (``_label_components``).  Each is linked both ways with the sampled
+    core nodes nearest to two of its members: the member nearest the core
+    (the cross pair, which a search can follow) and its root (its seed,
+    which reaches all of it; it links back into a free slot only).  A link
+    into a full row takes its last slot; the passes repeat until every
+    row is reachable, since a later link can evict an earlier one.  A
+    pruned graph can leave many small components (about a fifth of all
+    rows on DEEP-like data without reverse candidates), so members are
+    scored against the core sample in ``LINK_BLOCK``-row matmuls and
+    components are sliced from one sort."""
     rng = np.random.default_rng(seed)
     n = x.shape[0]
     nb = neighbors.copy()
-    for _ in range(4):  # almost always 1 pass; re-check for rare overwrites
-        comp = np.full(n, -1, np.int64)
-        n_comp = 0
-        todo = np.concatenate([[entry], np.arange(n)])
-        for seed_node in todo:
-            if comp[seed_node] >= 0:
-                continue
-            frontier = np.array([seed_node])
-            comp[seed_node] = n_comp
-            while len(frontier):
-                nxt = nb[frontier].reshape(-1)
-                nxt = nxt[nxt < n]
-                # treat edges as undirected for labeling (reverse edges were
-                # added; residual one-way edges still join weak components)
-                nxt = np.unique(nxt)
-                nxt = nxt[comp[nxt] < 0]
-                comp[nxt] = n_comp
-                frontier = nxt
-            n_comp += 1
+
+    def link(u: int, v: int, evict: bool) -> None:
+        row = nb[u]
+        deg = int((row < n).sum())
+        if (row[:deg] == v).any() or (deg == len(row) and not evict):
+            return
+        nb[u, min(deg, len(row) - 1)] = v
+
+    for _ in range(4):  # almost always 1 pass; re-check after evictions
+        comp, n_comp = _label_components(nb, n, entry)
         if n_comp == 1:
             return nb
-        core_ids = np.flatnonzero(comp == 0)
+        order = np.argsort(comp, kind="stable")     # ascending id per comp
+        bounds = np.searchsorted(comp[order], np.arange(n_comp + 1))
+        core_ids = order[:bounds[1]]
         rs = core_ids if len(core_ids) <= sample else \
             rng.choice(core_ids, sample, replace=False)
+        xr = x[rs]
+        rest = order[bounds[1]:]
+        near = np.empty(len(rest), np.int64)
+        near_d = np.empty(len(rest), np.float32)
+        for s in range(0, len(rest), LINK_BLOCK):
+            d2 = pairwise_sq_dists(x[rest[s:s + LINK_BLOCK]], xr)
+            near[s:s + LINK_BLOCK] = np.argmin(d2, axis=1)
+            near_d[s:s + LINK_BLOCK] = d2[np.arange(len(d2)),
+                                          near[s:s + LINK_BLOCK]]
         for c in range(1, n_comp):
-            comp_ids = np.flatnonzero(comp == c)
-            cs = comp_ids if len(comp_ids) <= sample else \
-                rng.choice(comp_ids, sample, replace=False)
-            d2 = pairwise_sq_dists(x[cs], x[rs])
-            i, j = np.unravel_index(np.argmin(d2), d2.shape)
-            a, b = int(rs[j]), int(cs[i])  # a in core, b in component
-            for s, t in ((a, b), (b, a)):
-                row = nb[s]
-                deg = int((row < n).sum())
-                if (row[:deg] == t).any():
-                    continue
-                slot = deg if deg < row.shape[0] else row.shape[0] - 1
-                nb[s, slot] = t
-        if bfs_reachable(nb, n, entry).all():
-            return nb
+            lo, hi = bounds[c] - bounds[1], bounds[c + 1] - bounds[1]
+            i = lo + int(np.argmin(near_d[lo:hi]))
+            members = (i,) if i == lo else (i, lo)   # cross pair, root
+            for m in members:
+                a, b = int(rs[near[m]]), int(rest[m])
+                link(a, b, True)
+                link(b, a, m == i)
     return nb
+
+
+class PhaseTimer:
+    """Wall-clock laps of a build into ``times[prefix + name]``; the first
+    lap starts when the timer is made."""
+
+    def __init__(self, times: Optional[Dict[str, float]], prefix: str = ""):
+        self.times = times
+        self.prefix = prefix
+        self.t = time.perf_counter()
+
+    def lap(self, name: str) -> None:
+        now = time.perf_counter()
+        if self.times is not None:
+            self.times[self.prefix + name] = now - self.t
+        self.t = now
 
 
 def build_graph(x: np.ndarray, R: int = 32, *, method: str = "auto",
                 alpha: float = 1.2, knn_k: Optional[int] = None,
                 seed: int = 0, reverse: bool = True,
-                repair: bool = True) -> Graph:
+                repair: bool = True,
+                clock: Optional[PhaseTimer] = None) -> Graph:
     """Construct a navigable graph.
     method: exact | clustered | nn_descent | auto.  ``nn_descent`` is the
     device-resident CAGRA-style builder (core/device_build, DESIGN.md §9):
     NN-descent candidate lists + device occlusion prune; the reverse /
-    connectivity passes below are shared."""
+    connectivity passes below are shared.  ``clock`` (optional) records
+    the wall seconds of the steps knn (``nn_descent``: knn_seed and
+    knn_rounds), prune, reverse and connect."""
     n = x.shape[0]
     x = np.ascontiguousarray(x, np.float32)
     knn_k = knn_k or min(n - 1, 2 * R)
@@ -395,8 +443,9 @@ def build_graph(x: np.ndarray, R: int = 32, *, method: str = "auto",
     if method == "nn_descent":
         from repro.core import device_build
         return device_build.build_graph_device(
-            x, R, alpha=alpha, knn_k=knn_k, seed=seed,
-            reverse=reverse, repair=repair)
+            x, R, alpha=alpha, knn_k=knn_k,
+            reverse=reverse, repair=repair, clock=clock)
+    clock = clock or PhaseTimer(None)
     if method == "exact":
         ids, dd = brute_knn(x, knn_k)
     elif method == "clustered":
@@ -405,11 +454,24 @@ def build_graph(x: np.ndarray, R: int = 32, *, method: str = "auto",
     else:
         raise ValueError(f"unknown build method {method!r} "
                          f"(exact | clustered | nn_descent | auto)")
+    clock.lap("knn")
     nb = occlusion_prune(x, ids, dd, R, alpha=alpha)
+    clock.lap("prune")
+    return _finish_graph(nb, x, R, reverse=reverse, repair=repair,
+                         clock=clock)
+
+
+def _finish_graph(nb: np.ndarray, x: np.ndarray, R: int, *, reverse: bool,
+                  repair: bool, clock: PhaseTimer) -> Graph:
+    """The passes every build method shares: reverse-edge augmentation
+    and the NSG-style connectivity repair (host numpy)."""
+    n = x.shape[0]
     if reverse:
         nb = add_reverse_edges(nb, n, R)
+        clock.lap("reverse")
     if repair and n > 1:
         nb = connect_components(nb, x, medoid(x))
+        clock.lap("connect")
     return Graph(nb.astype(np.int32), n)
 
 

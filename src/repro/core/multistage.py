@@ -68,11 +68,9 @@ class SearchParams:
     # stage ①.  1 = the classic single-frontier round (bit-identical).
     frontier_width: int = 1
     frontier_width_pilot: int = 1
-    # stage ① via the fused Pallas hop kernel (DESIGN.md §3).
-    # pallas_interpret=True emulates the kernel on CPU (tests/benchmarks);
-    # set False on real TPU to run the compiled kernel.
+    # stage ① via the fused Pallas hop kernel (DESIGN.md §3); compiled on
+    # an accelerator, interpreted on the CPU.
     use_pallas_traversal: bool = False
-    pallas_interpret: bool = True
     # stage ① via the persistent whole-search kernel (one pallas_call for the
     # entire pilot search; implies the fused hop path).  DESIGN.md §3.
     use_persistent_traversal: bool = False
@@ -289,7 +287,6 @@ def multistage_search(arrays: Dict[str, jax.Array], params: SearchParams,
                                 frontier_width=params.frontier_width_pilot,
                                 use_pallas=(params.use_pallas_traversal or
                                             params.use_persistent_traversal),
-                                pallas_interpret=params.pallas_interpret,
                                 use_persistent=params.use_persistent_traversal)
         st1 = T.greedy_search(spec1, q_primary, arrays["sub_neighbors"],
                               arrays["primary"], nk, entry_pilot,

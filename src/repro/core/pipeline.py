@@ -73,7 +73,6 @@ def _pilot_spec(params: SearchParams) -> T.TraversalSpec:
                            frontier_width=params.frontier_width_pilot,
                            use_pallas=(params.use_pallas_traversal or
                                        params.use_persistent_traversal),
-                           pallas_interpret=params.pallas_interpret,
                            use_persistent=params.use_persistent_traversal)
 
 
@@ -93,6 +92,7 @@ class _DonatedStages:
 
     def __init__(self, arrays: Dict[str, jax.Array], params: SearchParams):
         self.params = params
+        self.arrays = arrays
         self.nk = arrays["pilot_to_full"].shape[0] - 1
         n = arrays["rot_vecs"].shape[0] - 1
         pilot_scale = arrays.get("primary_scale")
@@ -103,8 +103,8 @@ class _DonatedStages:
         self._pallas = (params.use_pallas_traversal or
                         params.use_persistent_traversal)
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def pilot_fn(queries, visited_scratch, pilot_tomb=None):
+        @partial(jax.jit, donate_argnums=(2,))
+        def pilot_fn(arrays, queries, visited_scratch, pilot_tomb=None):
             # clear the recycled filter in place (donated: output aliases it)
             cleared = visited_scratch ^ visited_scratch
             qp = queries[:, :dp]
@@ -122,9 +122,9 @@ class _DonatedStages:
                                   tombstone=pilot_tomb)
             return st1.cand_id, st1.cand_d, st1.visited
 
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def cpu_fn(queries, cand_id, cand_dp, visited, pilot_tomb=None,
-                   tomb=None):
+        @partial(jax.jit, donate_argnums=(2, 3, 4))
+        def cpu_fn(arrays, queries, cand_id, cand_dp, visited,
+                   pilot_tomb=None, tomb=None):
             Bq = queries.shape[0]
             arr = arrays if pilot_tomb is None else dict(
                 arrays, pilot_tombstone=pilot_tomb, tombstone=tomb)
@@ -159,11 +159,11 @@ class _DonatedStages:
         pool = self._pool.get(Bq)
         scratch = pool.pop() if pool else visited_buffer(self.params, Bq,
                                                          self.nk)
-        return self._pilot_fn(queries, scratch, *tombs)
+        return self._pilot_fn(self.arrays, queries, scratch, *tombs)
 
     def cpu(self, queries: jax.Array, cand_id, cand_dp, visited, *tombs):
-        ids, dists, _cid, _cd, vis_r = self._cpu_fn(queries, cand_id,
-                                                    cand_dp, visited, *tombs)
+        ids, dists, _cid, _cd, vis_r = self._cpu_fn(
+            self.arrays, queries, cand_id, cand_dp, visited, *tombs)
         self._pool.setdefault(queries.shape[0], []).append(vis_r)
         return ids, dists
 
@@ -349,7 +349,10 @@ def split_stages(arrays: Dict[str, jax.Array], params: SearchParams,
     ``pilot_stage(queries, pilot_tomb)`` / ``cpu_stages(..., pilot_tomb,
     tomb)`` — so deletes flow into already-compiled executables without a
     retrace (closure-captured arrays would be baked in as constants);
-    omitted, the immutable traces carry no masking ops.
+    omitted, the immutable traces carry no masking ops.  The index arrays
+    themselves are arguments of both executables too: baked in as
+    constants, a 1M-row index made a 2.9 GB TPU executable that compiled for
+    minutes and exceeded the persistent compile cache's entry limit.
 
     shard_ctx (a ``distributed.ShardContext``) selects the pod-sharded
     variant (DESIGN.md §7): the stages become ``shard_map`` programs over
@@ -370,7 +373,7 @@ def split_stages(arrays: Dict[str, jax.Array], params: SearchParams,
                            codebook=pilot_codebook)
 
     @jax.jit
-    def pilot_stage(queries, pilot_tomb=None):
+    def pilot_stage(arrays, queries, pilot_tomb=None):
         B0 = queries.shape[0]
         qpad, _ = pad_for_pallas(queries, params)
         qp = qpad[:, :dp]
@@ -388,8 +391,8 @@ def split_stages(arrays: Dict[str, jax.Array], params: SearchParams,
         return st1.cand_id[:B0], st1.cand_d[:B0], st1.visited[:B0]
 
     @jax.jit
-    def cpu_stages(queries, cand_id, cand_dp, visited, pilot_tomb=None,
-                   tomb=None):
+    def cpu_stages(arrays, queries, cand_id, cand_dp, visited,
+                   pilot_tomb=None, tomb=None):
         Bq = queries.shape[0]
         arr = arrays if pilot_tomb is None else dict(
             arrays, pilot_tombstone=pilot_tomb, tombstone=tomb)
@@ -406,7 +409,7 @@ def split_stages(arrays: Dict[str, jax.Array], params: SearchParams,
                               tombstone=tomb)
         return T.topk_from_state(st3, params.k)
 
-    return pilot_stage, cpu_stages
+    return partial(pilot_stage, arrays), partial(cpu_stages, arrays)
 
 
 def degrade_params(params: SearchParams, scale: float = 0.5) -> SearchParams:

@@ -313,6 +313,7 @@ def pq_lut(q: jax.Array, codebook: jax.Array) -> jax.Array:
     cn = jnp.sum(cb * cb, axis=0)                          # (m·ksub,)
     dot = jax.lax.dot_general(q.astype(jnp.float32), cb,
                               (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     return cn[None, :] - 2.0 * dot
 

@@ -58,11 +58,10 @@ class TraversalSpec:
     state_spec: Optional[object] = None
     dense_visited_update: bool = False
     # fused Pallas hop (kernels/traversal_kernel.py, DESIGN.md §3): one
-    # kernel per expansion round instead of the op-by-op body below.
-    # pallas_interpret runs the kernel through the Pallas interpreter
-    # (CPU-correct; compiled lowering is for real TPU runs).
+    # kernel per expansion round instead of the op-by-op body below.  The
+    # kernel is compiled on an accelerator and interpreted on the CPU
+    # (kernels/backend.resolve_interpret).
     use_pallas: bool = False
-    pallas_interpret: bool = True
     # persistent stage-① kernel (kernels/traversal_kernel.fused_pilot_search):
     # the whole search — frontier selection, gather, visited filter,
     # distances, merge, convergence — runs inside ONE pallas_call with a
@@ -91,14 +90,23 @@ def sq_dists(q: jax.Array, vecs: jax.Array) -> jax.Array:
     Formulated as norms - 2·dot so the contraction is a matmul (MXU-dense on
     TPU; the FES kernel uses the same identity with cluster tiling).  This is
     the single source of truth for the norms-minus-2dot identity; callers
-    (stage ② re-rank, coarse entry layer) reuse it instead of open-coding."""
+    (stage ② re-rank, coarse entry layer) reuse it instead of open-coding.
+    The contraction names ``Precision.HIGHEST``.  A shared-table matmul
+    runs on the MXU, which at the default precision rounds f32 inputs to
+    bf16 (a one-hot id gather that way was off by up to 511 on a v5e).
+    The batched form measured no such error on a v5e (stage-③ distances
+    within 2.92e-7 of the norms at the default precision, 3.25e-7 at
+    HIGHEST), so there the setting states what stages ②/③ need rather
+    than repairs a measured error."""
     q = q.astype(jnp.float32)
     vecs = vecs.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1)[:, None]
     vn = jnp.sum(vecs * vecs, axis=-1)
+    hi = jax.lax.Precision.HIGHEST
     if vecs.ndim == 2:                     # one shared (m, d) table
-        return jnp.maximum(qn + vn[None, :] - 2.0 * (q @ vecs.T), 0.0)
-    dot = jnp.einsum("bd,brd->br", q, vecs)
+        dot = jnp.matmul(q, vecs.T, precision=hi)
+        return jnp.maximum(qn + vn[None, :] - 2.0 * dot, 0.0)
+    dot = jnp.einsum("bd,brd->br", q, vecs, precision=hi)
     return jnp.maximum(qn + vn - 2.0 * dot, 0.0)
 
 
@@ -285,8 +293,8 @@ def _pallas_round(spec: TraversalSpec, state: SearchState, queries: jax.Array,
     new_id, new_d, new_ck, visited, fresh = fused_traversal_hop(
         queries, neighbor_table, vector_table, state.cand_id, state.cand_d,
         state.checked, state.visited, n, width=spec.frontier_width,
-        visited_mode=spec.visited_mode, interpret=spec.pallas_interpret,
-        vec_scale=vec_scale, vec_codebook=vec_codebook)
+        visited_mode=spec.visited_mode, vec_scale=vec_scale,
+        vec_codebook=vec_codebook)
     return SearchState(
         cand_id=new_id,
         cand_d=new_d,
@@ -364,8 +372,7 @@ def greedy_search(spec: TraversalSpec, queries: jax.Array,
                 queries, neighbor_table, vector_table, state.cand_id,
                 state.cand_d, state.checked, state.visited, n,
                 rounds=rounds, width=spec.frontier_width,
-                visited_mode=spec.visited_mode,
-                interpret=spec.pallas_interpret, vec_scale=vec_scale,
+                visited_mode=spec.visited_mode, vec_scale=vec_scale,
                 vec_codebook=vec_codebook)
             return SearchState(cand_id=nid, cand_d=nd, checked=nck,
                                visited=nvis, n_dist=state.n_dist + d_dist,

@@ -19,10 +19,13 @@ Tile sizes are 128-aligned (MXU systolic dims / VREG lanes).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret
 
 
 def _fes_tile_kernel(q_ref, ev_ref, s_ref, o_ref):
@@ -36,6 +39,7 @@ def _fes_tile_kernel(q_ref, ev_ref, s_ref, o_ref):
     qn = jnp.sum(q * q, axis=-1, keepdims=True)            # (QC, 1)
     en = jnp.sum(e * e, axis=-1, keepdims=True)            # (Ct, 1)
     dot = jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     part = qn + en.T - 2.0 * dot                           # (QC, Ct)
 
@@ -64,6 +68,7 @@ def _fes_int4_kernel(q_ref, ev_ref, s_ref, o_ref):
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
     en = jnp.sum(e * e, axis=-1, keepdims=True)
     dot = jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[0] = qn + en.T - 2.0 * dot
 
@@ -78,6 +83,7 @@ def _fes_pq_kernel(q_ref, ev_ref, cb_ref, o_ref, *, m: int, ksub: int):
     cb = cb_ref[...].astype(jnp.float32)           # (dp, m·ksub)
     cn = jnp.sum(cb * cb, axis=0)
     dot = jax.lax.dot_general(q, cb, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     lut = cn[None, :] - 2.0 * dot                  # (QC, m·ksub)
     codes = ev_ref[0].astype(jnp.int32)            # (Ct, m)
@@ -89,6 +95,7 @@ def _fes_pq_kernel(q_ref, ev_ref, cb_ref, o_ref, *, m: int, ksub: int):
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
     adc = jax.lax.dot_general(lut, hot.astype(jnp.float32),
                               (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[0] = qn + adc
 
@@ -96,7 +103,7 @@ def _fes_pq_kernel(q_ref, ev_ref, cb_ref, o_ref, *, m: int, ksub: int):
 def fes_distances(q_grouped: jax.Array, entries: jax.Array, *,
                   scale: jax.Array = None, codebook: jax.Array = None,
                   c_tile: int = 128, d_tile: int = 128,
-                  interpret: bool = False) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """q_grouped: (r, QC, d) cluster-grouped (padded) queries;
     entries: (r, C, d) cluster-bucketed entry vectors — stored fp32, bf16
     or int8 (pass the per-dim ``scale`` (d,) for int8), nibble-packed int4
@@ -110,6 +117,7 @@ def fes_distances(q_grouped: jax.Array, entries: jax.Array, *,
     assert entries.shape[0] == r
     ct = min(c_tile, C)
     assert C % ct == 0, (C, ct)
+    interpret = resolve_interpret(interpret)
 
     if codebook is not None:                       # pq: ADC LUT matmuls
         mk = codebook.shape[1]

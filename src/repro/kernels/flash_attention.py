@@ -14,10 +14,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -37,12 +40,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
     def body(j, carry):
         m, l, acc = carry
-        # index the unit leading dim with a size-1 dslice: some jax versions
-        # reject bare ints in pl.load index tuples
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)   # (bk, d)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)   # (bk, d)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
@@ -68,8 +67,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = False
-                        ) -> jax.Array:
+                        block_k: int = 128,
+                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D) with H % Hkv == 0.
     Returns (B, Sq, H, D).  Sq % block_q == 0 and Sk % block_k == 0
     (callers pad; see ops)."""
@@ -97,6 +96,6 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

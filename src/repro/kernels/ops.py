@@ -31,7 +31,7 @@ def _pad_to(x: jax.Array, axis: int, size: int, value=0):
 @functools.partial(jax.jit, static_argnames=("L", "qc", "interpret"))
 def fes_select(queries: jax.Array, centroids: jax.Array, entries: jax.Array,
                entry_ids: jax.Array, valid: jax.Array, *, L: int,
-               qc: Optional[int] = None, interpret: bool = True,
+               qc: Optional[int] = None, interpret: Optional[bool] = None,
                entries_scale: Optional[jax.Array] = None,
                entries_codebook: Optional[jax.Array] = None,
                tombstone: Optional[jax.Array] = None
@@ -58,7 +58,8 @@ def fes_select(queries: jax.Array, centroids: jax.Array, entries: jax.Array,
     # ---- route ----
     qn = jnp.sum(q * q, -1)[:, None]
     cn = jnp.sum(centroids * centroids, -1)[None, :]
-    d2c = qn + cn - 2.0 * (q @ centroids.T)
+    d2c = qn + cn - 2.0 * jnp.matmul(q, centroids.T,
+                                     precision=jax.lax.Precision.HIGHEST)
     route = jnp.argmin(d2c, axis=1).astype(jnp.int32)      # (B,)
 
     # ---- group queries by cluster (sort once, pad per cluster to qc) ----

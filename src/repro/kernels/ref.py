@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-BIG = jnp.float32(3.0e38)
+BIG = 3.0e38  # python float, as kernels/topk_kernel.BIG
 
 
 def fes_distances_ref(q_grouped: jax.Array, entries: jax.Array,
@@ -24,6 +24,7 @@ def fes_distances_ref(q_grouped: jax.Array, entries: jax.Array,
         cb = codebook.astype(jnp.float32)
         cn = jnp.sum(cb * cb, axis=0)              # (m·ksub,)
         dot = jax.lax.dot_general(q, cb, (((2,), (0,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
                                   preferred_element_type=jnp.float32)
         lut = cn[None, None, :] - 2.0 * dot        # (r, QC, m·ksub)
         codes = entries.astype(jnp.int32)          # (r, C, m)
@@ -36,6 +37,7 @@ def fes_distances_ref(q_grouped: jax.Array, entries: jax.Array,
         adc = jax.lax.dot_general(
             lut, hot.astype(jnp.float32),
             (((2,), (2,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)    # (r, QC, C)
         return qn + adc
     if scale is not None and entries.shape[-1] < scale.shape[0]:   # int4
@@ -49,7 +51,8 @@ def fes_distances_ref(q_grouped: jax.Array, entries: jax.Array,
         e = e * scale.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1)[..., :, None]
     en = jnp.sum(e * e, axis=-1)[..., None, :]
-    dot = jnp.einsum("rqd,rcd->rqc", q, e)
+    dot = jnp.einsum("rqd,rcd->rqc", q, e,
+                     precision=jax.lax.Precision.HIGHEST)
     return qn + en - 2.0 * dot
 
 
@@ -72,6 +75,7 @@ def _pilot_oracle_operands(q, vec_table, vec_scale, vec_codebook):
         cn = jnp.sum(cb * cb, axis=0)
         lut = cn[None, :] - 2.0 * jax.lax.dot_general(
             qf, cb, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         return qf, vec_table, None, lut
     if vec_scale is not None and vec_table.shape[1] < vec_scale.shape[0]:
@@ -139,7 +143,8 @@ def traversal_hop_ref(q, nbr_table, vec_table, beam_id, beam_d, beam_ck,
         if vec_scale is not None:
             nv = nv * vec_scale.astype(jnp.float32)
         vn = jnp.sum(nv * nv, axis=-1)
-        dot = jnp.einsum("bd,brd->br", qf, nv)
+        dot = jnp.einsum("bd,brd->br", qf, nv,
+                     precision=jax.lax.Precision.HIGHEST)
         d = jnp.maximum(qn + vn - 2.0 * dot, 0.0)
     d = jnp.where(fresh, d, jnp.inf)
 
@@ -216,7 +221,8 @@ def expand_merge_ref(q, nvecs, nids, fresh, beam_id, beam_d, beam_ck, n: int):
     nv = nvecs.astype(jnp.float32)
     qn = jnp.sum(qf * qf, axis=-1)[:, None]
     vn = jnp.sum(nv * nv, axis=-1)
-    dot = jnp.einsum("bd,brd->br", qf, nv)
+    dot = jnp.einsum("bd,brd->br", qf, nv,
+                     precision=jax.lax.Precision.HIGHEST)
     d = jnp.maximum(qn + vn - 2.0 * dot, 0.0)
     d = jnp.where(fresh, d, BIG)
 

@@ -16,10 +16,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 BIG = 3.0e38  # python float: +inf stand-in that survives bitonic compares
 
@@ -28,40 +32,42 @@ def _next_pow2(x: int) -> int:
     return 1 << (x - 1).bit_length()
 
 
-def _bitonic_sort_pairs(keys: jax.Array, vals: jax.Array, flags: jax.Array):
-    """Ascending bitonic sort of (B, W) keys with two carried payloads.
-    W must be a power of two.  Pure jnp (reshape/where) — lowers inside
-    Pallas on TPU and in interpret mode."""
+def bitonic_sort(keys: jax.Array, tie: jax.Array, *payloads: jax.Array):
+    """Ascending bitonic sort of (B, W) rows by the pair (keys, tie),
+    carrying ``payloads`` along.  W must be a power of two.
+
+    Written for Mosaic's TPU lowering: the partner exchange is two lane
+    rolls and a select (``_swap_lanes``), and every compare-exchange is
+    boolean logic feeding a select on 32-bit values — no lane reversal,
+    no boolean-valued select.  Returns ``(keys, tie, *payloads)``."""
     B, W = keys.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
     stages = int(math.log2(W))
     for s in range(stages):
         for t in range(s, -1, -1):
             stride = 1 << t
-            idx = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
-            partner = idx ^ stride
-            asc = (idx & (1 << (s + 1))) == 0
-            k_p = _swap_lanes(keys, stride)
-            v_p = _swap_lanes(vals, stride)
-            f_p = _swap_lanes(flags, stride)
-            is_lo = partner > idx
-            keep = jnp.where(is_lo == asc,
-                             keys <= k_p,   # keep smaller at low lane if asc
-                             keys > k_p)
-            # tie-break deterministically by payload id
-            tie = keys == k_p
-            keep = jnp.where(tie, (vals <= v_p) == (is_lo == asc), keep)
+            k_p = _swap_lanes(keys, stride, lane)
+            t_p = _swap_lanes(tie, stride, lane)
+            # (keys, tie) <= partner's, lexicographically
+            less = (keys < k_p) | ((keys == k_p) & (tie <= t_p))
+            # lanes whose pair sorts ascending and that hold its low end
+            # keep the smaller element; the others keep the larger
+            up = (((lane >> t) ^ (lane >> (s + 1))) & 1) == 0
+            keep = ~(less ^ up)
             keys = jnp.where(keep, keys, k_p)
-            vals = jnp.where(keep, vals, v_p)
-            flags = jnp.where(keep, flags, f_p)
-    return keys, vals, flags
+            tie = jnp.where(keep, tie, t_p)
+            payloads = tuple(jnp.where(keep, p, _swap_lanes(p, stride, lane))
+                             for p in payloads)
+    return (keys, tie) + payloads
 
 
-def _swap_lanes(x: jax.Array, stride: int) -> jax.Array:
-    """Exchange lanes with partner (index ^ stride) via reshape/flip."""
-    B, W = x.shape
-    y = x.reshape(B, W // (2 * stride), 2, stride)
-    y = jnp.flip(y, axis=2)
-    return y.reshape(B, W)
+def _swap_lanes(x: jax.Array, stride: int, lane: jax.Array) -> jax.Array:
+    """Exchange every lane with its partner ``lane ^ stride``: two lane
+    rotations (``pltpu.roll`` follows ``jnp.roll``) and a select."""
+    W = x.shape[1]
+    nxt = pltpu.roll(x, W - stride, 1)        # nxt[i] = x[i + stride]
+    prv = pltpu.roll(x, stride, 1)            # prv[i] = x[i - stride]
+    return jnp.where((lane & stride) == 0, nxt, prv)
 
 
 def _expand_merge_kernel(q_ref, nvec_ref, nid_ref, fresh_ref,
@@ -70,13 +76,11 @@ def _expand_merge_kernel(q_ref, nvec_ref, nid_ref, fresh_ref,
     q = q_ref[...].astype(jnp.float32)                     # (Bt, d)
     nv = nvec_ref[...].astype(jnp.float32)                 # (Bt, R, d)
     nid = nid_ref[...]                                     # (Bt, R)
-    fresh = fresh_ref[...]                                 # (Bt, R) bool
+    fresh = fresh_ref[...] != 0                            # (Bt, R) int32
 
     qn = jnp.sum(q * q, axis=-1)[:, None]
     vn = jnp.sum(nv * nv, axis=-1)
-    dot = jax.lax.dot_general(nv, q[:, :, None],
-                              (((2,), (1,)), ((0,), (0,))),
-                              preferred_element_type=jnp.float32)[..., 0]
+    dot = jnp.sum(nv * q[:, None, :], axis=-1)
     d = jnp.maximum(qn + vn - 2.0 * dot, 0.0)              # (Bt, R)
     d = jnp.where(fresh, d, BIG)
 
@@ -89,22 +93,23 @@ def _expand_merge_kernel(q_ref, nvec_ref, nid_ref, fresh_ref,
         [bid_ref[...], jnp.where(fresh, nid, n)] +
         ([jnp.full((Bt, pad), n, jnp.int32)] if pad else []), axis=1)
     flags = jnp.concatenate(
-        [bck_ref[...].astype(jnp.int32), (~fresh).astype(jnp.int32)] +
+        [bck_ref[...], 1 - fresh_ref[...]] +
         ([jnp.ones((Bt, pad), jnp.int32)] if pad else []), axis=1)
 
-    keys, vals, flags = _bitonic_sort_pairs(keys, vals, flags)
+    keys, vals, flags = bitonic_sort(keys, vals, flags)
     od_ref[...] = keys[:, :ef]
     oid_ref[...] = vals[:, :ef]
-    ock_ref[...] = flags[:, :ef] != 0
+    ock_ref[...] = flags[:, :ef]
 
 
 def fused_expand_merge(q: jax.Array, nvecs: jax.Array, nids: jax.Array,
                        fresh: jax.Array, beam_id: jax.Array, beam_d: jax.Array,
                        beam_ck: jax.Array, n: int, *, b_tile: int = 128,
-                       interpret: bool = False):
+                       interpret: Optional[bool] = None):
     """q (B, d); nvecs (B, R, d); nids/fresh (B, R);
     beam_* (B, ef) sorted beam.  Returns merged (ids, dists, checked) (B, ef).
-    Non-fresh rows enter with +INF distance (dropped unless beam not full)."""
+    Non-fresh rows enter with +INF distance (dropped unless beam not full).
+    Boolean operands cross the kernel boundary as int32."""
     B, d = q.shape
     R = nids.shape[1]
     ef = beam_id.shape[1]
@@ -117,7 +122,7 @@ def fused_expand_merge(q: jax.Array, nvecs: jax.Array, nids: jax.Array,
     out_shapes = (
         jax.ShapeDtypeStruct((B, ef), jnp.int32),
         jax.ShapeDtypeStruct((B, ef), jnp.float32),
-        jax.ShapeDtypeStruct((B, ef), bool),
+        jax.ShapeDtypeStruct((B, ef), jnp.int32),
     )
     oid, od, ock = pl.pallas_call(
         kern,
@@ -137,6 +142,7 @@ def fused_expand_merge(q: jax.Array, nvecs: jax.Array, nids: jax.Array,
             pl.BlockSpec((bt, ef), lambda i: (i, 0)),
         ),
         out_shape=out_shapes,
-        interpret=interpret,
-    )(q, nvecs, nids, fresh, beam_id, beam_d, beam_ck)
-    return oid, od, ock
+        interpret=resolve_interpret(interpret),
+    )(q, nvecs, nids, fresh.astype(jnp.int32), beam_id, beam_d,
+      beam_ck.astype(jnp.int32))
+    return oid, od, ock != 0

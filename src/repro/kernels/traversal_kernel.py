@@ -19,11 +19,19 @@ the gathered neighbour vectors, the (B, W·R) distance block, and the
   with the per-hop path under both fixed budgets and run-to-convergence.
 
 TPU adaptation notes (DESIGN.md §3 spells out the full contract):
-  * gathers are *one-hot matmuls*: ``onehot(u) @ table`` is MXU-dense and
-    lowers everywhere, unlike a dynamic row gather from VMEM.  This requires
-    node ids to be fp32-exact (n < 2**24) and is why the pilot index — not
-    the full corpus — is the target: the replicated subgraph tables are
-    sized to fit on-chip (paper §4.1).
+  * gathers are *one-hot matmuls*: ``onehot(u) @ table`` runs on the MXU
+    where a dynamic row gather from VMEM does not lower.  The matmul runs at
+    ``Precision.HIGHEST``: at the TPU's default precision its operands are
+    rounded to bf16, which holds integers exactly only up to 256.  It needs
+    node ids that are fp32-exact (n < 2**24), and both tables whole in VMEM
+    next to (bt, Npad) one-hot transients, so ``VMEM_LIMIT_BYTES`` — not
+    HBM — bounds the pilot these kernels serve (DESIGN.md §3 gives the
+    largest pilot that compiles for a v5e; above it the compiler's refusal
+    propagates).
+  * Mosaic lowers no ``rev`` or ``cumsum`` and no boolean vector crossing
+    memory: the bitonic lane exchange is two ``pltpu.roll`` calls and a
+    select, frontier selection takes the lowest unchecked lane W times, and
+    flags cross the kernel boundary as int32 0/1.
   * the visited structure (bloom filter or exact bitmap) is updated with the
     scatter-free one-hot form of ``core.bloom.bloom_insert_dense``, looped
     over the neighbour slots so the transient stays (bt, n_bits).  Frontiers
@@ -60,50 +68,17 @@ ids to fp32 either way.
 from __future__ import annotations
 
 import functools
-import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_kernel import BIG, _next_pow2, _swap_lanes
-
-
-def _bitonic_sort_stable(keys, vals, flags):
-    """Ascending bitonic sort of (B, W) keys carrying (vals, flags), with
-    ties broken by *original lane position* — i.e. a stable sort, matching
-    ``jnp.argsort``'s behaviour in the unfused merge exactly, including on
-    tied distances (duplicate vectors).  W must be a power of two.
-
-    Same compare-exchange schedule as topk_kernel._bitonic_sort_pairs, which
-    instead ties on the id payload (fine for its callers, where equal keys
-    imply equal sentinel ids)."""
-    Bq, W = keys.shape
-    pos = jnp.broadcast_to(
-        jax.lax.broadcasted_iota(jnp.int32, (Bq, W), 1), (Bq, W))
-    stages = int(math.log2(W))
-    for s in range(stages):
-        for t in range(s, -1, -1):
-            stride = 1 << t
-            idx = jax.lax.broadcasted_iota(jnp.int32, (Bq, W), 1)
-            partner = idx ^ stride
-            asc = (idx & (1 << (s + 1))) == 0
-            k_p = _swap_lanes(keys, stride)
-            v_p = _swap_lanes(vals, stride)
-            f_p = _swap_lanes(flags, stride)
-            p_p = _swap_lanes(pos, stride)
-            is_lo = partner > idx
-            keep = jnp.where(is_lo == asc, keys <= k_p, keys > k_p)
-            tie = keys == k_p
-            keep = jnp.where(tie, (pos <= p_p) == (is_lo == asc), keep)
-            keys = jnp.where(keep, keys, k_p)
-            vals = jnp.where(keep, vals, v_p)
-            flags = jnp.where(keep, flags, f_p)
-            pos = jnp.where(keep, pos, p_p)
-    return keys, vals, flags
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.topk_kernel import BIG, _next_pow2, bitonic_sort
 
 
 def _bloom_hashes(ids: jax.Array, n_bits: int):
@@ -115,6 +90,21 @@ def _bloom_hashes(ids: jax.Array, n_bits: int):
     h2 = (x * np.uint32(0xC2B2AE3D)) ^ (x >> 13) ^ (x * np.uint32(0x27D4EB2F))
     return ((h1 % np.uint32(n_bits)).astype(jnp.int32),
             (h2 % np.uint32(n_bits)).astype(jnp.int32))
+
+
+def _col(x: jax.Array, lane: jax.Array, j: int) -> jax.Array:
+    """Column ``j`` of a (bt, w) array as (bt, 1): a lane select and a
+    lane reduction, which Mosaic lowers at any lane offset."""
+    return jnp.sum(jnp.where(lane == j, x, 0), axis=1, keepdims=True)
+
+
+def _gather_rows(onehot: jax.Array, table: jax.Array) -> jax.Array:
+    """``onehot @ table`` at full fp32 precision: the one-hot matmul is a
+    row gather only if no operand is rounded to bf16 (ids above 256 and
+    fp32 vector entries are not bf16-exact)."""
+    return jax.lax.dot_general(onehot, table, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _round_body(q, qn, nbr_f, vec, row_iota, bit_iota, bid, bd, bck, vis, *,
@@ -129,98 +119,116 @@ def _round_body(q, qn, nbr_f, vec, row_iota, bit_iota, bid, bd, bck, vis, *,
     (bt-invariant, values 0..ksub-1) and distances come from per-query LUT
     gathers instead of MXU dot-products.
 
-    Distances stay in the BIG domain.  Returns
-    ``(new_id, new_d, new_ck, vis, fresh, n_sel, has_work)`` where fresh is
-    (bt, W·R), n_sel is the per-row count of expanded candidates and
-    has_work flags rows that had any unchecked candidate."""
+    Every array is 2-D; flags (``bck``, ``vis``, fresh) are int32 0/1, and
+    per-query scalars are (bt, 1) columns.  Distances stay in the BIG
+    domain.  Returns ``(new_id, new_d, new_ck, vis, fresh, n_sel,
+    has_work)`` where fresh is (bt, W·R), n_sel is the per-row count of
+    expanded candidates and has_work is 1 on rows that had any unchecked
+    candidate."""
     bt = bid.shape[0]
-    vpad = vis.shape[1]
+    WR = W * R
+    lane_ef = jax.lax.broadcasted_iota(jnp.int32, (bt, ef), 1)
+    lane_r = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)
+    lane_s = jax.lax.broadcasted_iota(jnp.int32, (bt, WR), 1)
 
-    # ---- frontier selection: top-W unchecked candidates per query (the
-    # beam is distance-sorted, so the first W unchecked slots are best) ----
-    unchecked = ~bck & (bid < n)
-    has_work = jnp.any(unchecked, axis=1)
-    cum = jnp.cumsum(unchecked.astype(jnp.int32), axis=1)
-    sel = unchecked & (cum <= W)
-    checked = bck | sel                                   # idle rows keep bck
-    n_sel = jnp.sum(sel.astype(jnp.int32), axis=1)
+    # ---- frontier selection: the W best unchecked candidates per query
+    # (the beam is distance-sorted, so they are the first W unchecked
+    # slots), one lowest-lane pick per frontier ----
+    avail = (bck == 0) & (bid < n)
+    has_work = jnp.max(avail.astype(jnp.int32), axis=1, keepdims=True)
+    checked = bck
+    n_sel = jnp.zeros((bt, 1), jnp.int32)
 
-    # ---- per frontier: one-hot gather + sequential visited filter ----
-    nbrs_cols, fresh_cols = [], []
+    # ---- per frontier: one-hot gather + sequential visited filter.  The
+    # slot loops are rolled (fori_loop): Mosaic emits every vector op once
+    # per vreg, so an unrolled loop over (bt, Npad) one-hots grows the
+    # kernel, and its compile time, with R·Npad ----
+    nbrs = jnp.full((bt, WR), n, jnp.int32)
+    fresh = jnp.zeros((bt, WR), jnp.int32)
     for w in range(W):
-        mask_w = sel & (cum == w + 1)
-        u_w = jnp.where(jnp.any(mask_w, axis=1),
-                        jnp.sum(jnp.where(mask_w, bid, 0), axis=1),
+        first = jnp.min(jnp.where(avail, lane_ef, ef), axis=1, keepdims=True)
+        pick = lane_ef == first                           # empty if first=ef
+        u_w = jnp.where(first < ef,
+                        jnp.sum(jnp.where(pick, bid, 0), axis=1,
+                                keepdims=True),
                         n)                                # sentinel row
-        onehot_u = (row_iota == u_w[:, None]).astype(jnp.float32)
-        nbrs_raw = jax.lax.dot_general(onehot_u, nbr_f,
-                                       (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-        nbrs_w = (nbrs_raw + 0.5).astype(jnp.int32)       # ids fp32-exact
-        valid = nbrs_w < n                                # (bt, R)
+        checked = jnp.where(pick, 1, checked)
+        avail = avail & ~pick
+        n_sel = n_sel + (first < ef).astype(jnp.int32)
+        onehot_u = (row_iota == u_w).astype(jnp.float32)
+        nbrs_w = (_gather_rows(onehot_u, nbr_f) + 0.5).astype(jnp.int32)
 
         if visited_mode == "bloom":
             h1, h2 = _bloom_hashes(nbrs_w, hash_bits)
         else:
-            h1 = h2 = jnp.clip(nbrs_w, 0, vpad - 1)
+            h1 = h2 = jnp.clip(nbrs_w, 0, vis.shape[1] - 1)
+
         # test all R slots against the filter as of this frontier (matches
         # the unfused round: within a frontier duplicates are each scored;
         # across frontiers, frontier w sees frontiers < w's inserts), then
         # union this frontier's inserts
-        ins = jnp.zeros_like(vis)
-        fresh_w = []
-        for r in range(R):
-            m1 = bit_iota == h1[:, r][:, None]
-            m2 = bit_iota == h2[:, r][:, None]
-            t = jnp.any(vis & m1, axis=1) & jnp.any(vis & m2, axis=1)
-            fr = valid[:, r] & ~t
-            ins = ins | ((m1 | m2) & fr[:, None])
-            fresh_w.append(fr)
-        vis = vis | ins
-        nbrs_cols.append(nbrs_w)
-        fresh_cols.append(jnp.stack(fresh_w, axis=1))
-    nbrs = jnp.concatenate(nbrs_cols, axis=1)             # (bt, W·R)
-    fresh = jnp.concatenate(fresh_cols, axis=1)
+        def slot(r, carry, vis=vis, nbrs_w=nbrs_w, h1=h1, h2=h2, w=w):
+            ins, nbrs, fresh = carry
+            id_r = _col(nbrs_w, lane_r, r)
+            m1 = bit_iota == _col(h1, lane_r, r)
+            m2 = bit_iota == _col(h2, lane_r, r)
+            seen = (jnp.max(jnp.where(m1, vis, 0), axis=1, keepdims=True)
+                    * jnp.max(jnp.where(m2, vis, 0), axis=1, keepdims=True))
+            fr = (id_r < n) & (seen == 0)                 # (bt, 1)
+            at = lane_s == w * R + r
+            return (jnp.where((m1 | m2) & fr, 1, ins),
+                    jnp.where(at, id_r, nbrs),
+                    jnp.where(at & fr, 1, fresh))
+
+        ins, nbrs, fresh = lax.fori_loop(
+            0, R, slot, (jnp.zeros_like(vis), nbrs, fresh))
+        vis = jnp.maximum(vis, ins)
 
     # ---- distances, one gather-matmul per slot: the MXU norms identity
     # for dense tables; for PQ payloads the gather fetches the m-byte code
     # row and the distance is qn + Σ_s lut[s·ksub + code_s] — one-hot LUT
     # gathers over the per-query ADC table, no d-wide dot-product ----
-    d_cols = []
     if lut is not None:
         lut_iota = jax.lax.broadcasted_iota(jnp.int32, lut.shape, 1)
         m = vec.shape[1]
-    for s in range(W * R):
-        onehot_r = (row_iota == nbrs[:, s][:, None]).astype(jnp.float32)
-        nv = jax.lax.dot_general(onehot_r, vec, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        lane_m = jax.lax.broadcasted_iota(jnp.int32, (bt, m), 1)
+
+    def score(s, d):
+        onehot_r = (row_iota == _col(nbrs, lane_s, s)).astype(jnp.float32)
+        nv = _gather_rows(onehot_r, vec)
         if lut is None:
-            vn = jnp.sum(nv * nv, axis=1)
-            dot = jnp.sum(nv * q, axis=1)
-            d_cols.append(jnp.maximum(qn + vn - 2.0 * dot, 0.0))
+            vn = jnp.sum(nv * nv, axis=1, keepdims=True)
+            dot = jnp.sum(nv * q, axis=1, keepdims=True)
+            d_s = jnp.maximum(qn + vn - 2.0 * dot, 0.0)
         else:
             crow = (nv + 0.5).astype(jnp.int32)           # codes fp32-exact
             acc = qn
             for sub in range(m):                          # fixed subspace
-                idx = ksub * sub + crow[:, sub]           # accumulation order
-                oh = lut_iota == idx[:, None]
-                acc = acc + jnp.sum(jnp.where(oh, lut, 0.0), axis=1)
-            d_cols.append(jnp.maximum(acc, 0.0))
-    d = jnp.where(fresh, jnp.stack(d_cols, axis=1), BIG)  # (bt, W·R)
+                idx = ksub * sub + _col(crow, lane_m, sub)  # accumulation
+                oh = lut_iota == idx                        # order
+                acc = acc + jnp.sum(jnp.where(oh, lut, 0.0), axis=1,
+                                    keepdims=True)
+            d_s = jnp.maximum(acc, 0.0)
+        return jnp.where(lane_s == s, d_s, d)
 
-    # ---- stable bitonic merge into the sorted beam ----
-    pad = Wsort - (ef + W * R)
+    d = lax.fori_loop(0, WR, score, jnp.full((bt, WR), BIG, jnp.float32))
+    d = jnp.where(fresh != 0, d, BIG)                     # (bt, W·R)
+
+    # ---- stable merge into the sorted beam: ties break on the original
+    # lane, which is what the unfused path's stable argsort does ----
+    pad = Wsort - (ef + WR)
     keys = jnp.concatenate(
         [bd, d] + ([jnp.full((bt, pad), BIG, jnp.float32)] if pad else []),
         axis=1)
     vals = jnp.concatenate(
-        [bid, jnp.where(fresh, nbrs, n)] +
+        [bid, jnp.where(fresh != 0, nbrs, n)] +
         ([jnp.full((bt, pad), n, jnp.int32)] if pad else []), axis=1)
     flags = jnp.concatenate(
-        [checked.astype(jnp.int32), (~fresh).astype(jnp.int32)] +
+        [checked, 1 - fresh] +
         ([jnp.ones((bt, pad), jnp.int32)] if pad else []), axis=1)
-    keys, vals, flags = _bitonic_sort_stable(keys, vals, flags)
-    return (vals[:, :ef], keys[:, :ef], flags[:, :ef] != 0, vis, fresh,
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bt, Wsort), 1)
+    keys, _, vals, flags = bitonic_sort(keys, pos, vals, flags)
+    return (vals[:, :ef], keys[:, :ef], flags[:, :ef], vis, fresh,
             n_sel, has_work)
 
 
@@ -239,10 +247,11 @@ def _decode_operands(q, vec_ref, scl_ref, cb_ref, encoding: str):
     Returns ``(vec, lut)`` with ``lut`` None except for ``pq``."""
     if encoding == "pq":
         cb = cb_ref[...].astype(jnp.float32)              # (dp8, m·ksub)
-        cn = jnp.sum(cb * cb, axis=0)
+        cn = jnp.sum(cb * cb, axis=0, keepdims=True)
         dot = jax.lax.dot_general(q, cb, (((1,), (0,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
                                   preferred_element_type=jnp.float32)
-        return vec_ref[...].astype(jnp.float32), cn[None, :] - 2.0 * dot
+        return vec_ref[...].astype(jnp.float32), cn - 2.0 * dot
     if encoding == "int4":
         v = vec_ref[...].astype(jnp.int32)
         lo = v & 0xF
@@ -250,8 +259,8 @@ def _decode_operands(q, vec_ref, scl_ref, cb_ref, encoding: str):
         hi = (v >> 4) & 0xF
         hi = jnp.where(hi >= 8, hi - 16, hi)
         unpacked = jnp.concatenate([lo, hi], axis=1).astype(jnp.float32)
-        return unpacked * scl_ref[0, :], None
-    return vec_ref[...].astype(jnp.float32) * scl_ref[0, :], None
+        return unpacked * scl_ref[0:1, :], None
+    return vec_ref[...].astype(jnp.float32) * scl_ref[0:1, :], None
 
 
 def _hop_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref, bd_ref,
@@ -263,7 +272,7 @@ def _hop_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref, bd_ref,
     bt = bid_ref.shape[0]
     Npad = nbr_ref.shape[0]
     vpad = vis_ref.shape[1]
-    qn = jnp.sum(q * q, axis=1)
+    qn = jnp.sum(q * q, axis=1, keepdims=True)
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, Npad), 1)
     bit_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, vpad), 1)
     vec, lut = _decode_operands(q, vec_ref, scl_ref, cb_ref, encoding)
@@ -294,7 +303,7 @@ def _persistent_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref,
     bt = bid_ref.shape[0]
     Npad = nbr_ref.shape[0]
     vpad = vis_ref.shape[1]
-    qn = jnp.sum(q * q, axis=1)
+    qn = jnp.sum(q * q, axis=1, keepdims=True)
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, Npad), 1)
     bit_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, vpad), 1)
     nbr_f = nbr_ref[...].astype(jnp.float32)              # hoisted operands
@@ -302,7 +311,8 @@ def _persistent_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref,
 
     def cond(carry):
         i, bid, _bd, bck, _vis, _nd, _nh, _ne = carry
-        return (i < rounds) & jnp.any(~bck & (bid < n))
+        work = jnp.max(jnp.where((bck == 0) & (bid < n), 1, 0))
+        return (i < rounds) & (work > 0)
 
     def body(carry):
         i, bid, bd, bck, vis, nd, nh, ne = carry
@@ -311,10 +321,10 @@ def _persistent_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref,
             n=n, R=R, W=W, ef=ef, Wsort=Wsort, hash_bits=hash_bits,
             visited_mode=visited_mode, lut=lut)
         return (i + 1, nid, nbd, nck, nvis,
-                nd + jnp.sum(fresh.astype(jnp.int32), axis=1),
-                nh + has_work.astype(jnp.int32), ne + n_sel)
+                nd + jnp.sum(fresh, axis=1, keepdims=True),
+                nh + has_work, ne + n_sel)
 
-    z = jnp.zeros((bt,), jnp.int32)
+    z = jnp.zeros((bt, 1), jnp.int32)
     carry = (jnp.int32(0), bid_ref[...], bd_ref[...], bck_ref[...],
              vis_ref[...], z, z, z)
     _, bid, bd, bck, vis, nd, nh, ne = lax.while_loop(cond, body, carry)
@@ -322,12 +332,17 @@ def _persistent_kernel(q_ref, nbr_ref, vec_ref, scl_ref, cb_ref, bid_ref,
     od_ref[...] = bd
     ock_ref[...] = bck
     ovis_ref[...] = vis
-    ocnt_ref[...] = jnp.concatenate(
-        [nd[:, None], nh[:, None], ne[:, None],
-         jnp.zeros((bt, _CNT_LANES - 3), jnp.int32)], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, _CNT_LANES), 1)
+    ocnt_ref[...] = jnp.where(lane == 0, nd, jnp.where(
+        lane == 1, nh, jnp.where(lane == 2, ne, 0)))
 
 
 _CNT_LANES = 8  # counters output: lanes 0..2 = (n_dist, n_hops, n_exp)
+# scoped VMEM the traversal kernels may use: they hold the whole pilot
+# table (both copies of the double buffer) plus (bt, Npad) one-hot
+# transients, so this, not HBM, bounds the pilot size they serve
+# (DESIGN.md §3).  A v5e core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 
 def align_tables(nbr_table: jax.Array, vec_table: jax.Array, n: int,
@@ -349,7 +364,9 @@ def _pad_state(q, nbr_table, vec_table, beam_id, beam_d, beam_ck, visited,
     """Shared wrapper-side padding: align table rows, pad visited lanes to a
     128 multiple and the batch to a b_tile multiple (idle all-checked
     sentinel beams, which also keeps padded rows out of the persistent
-    kernel's convergence check)."""
+    kernel's convergence check).  Boolean state leaves as int32 0/1: the
+    kernels keep booleans out of their operands (Mosaic cannot narrow a
+    stored byte to a vector mask)."""
     Bq = q.shape[0]
     vbits = visited.shape[1]
     nbr_t, vec_t = align_tables(nbr_table, vec_table, n)
@@ -367,7 +384,8 @@ def _pad_state(q, nbr_table, vec_table, beam_id, beam_d, beam_ck, visited,
         beam_ck = jnp.pad(beam_ck, ((0, pb), (0, 0)), constant_values=True)
         vis = jnp.pad(vis, ((0, pb), (0, 0)))
     bd = jnp.where(jnp.isfinite(beam_d), beam_d, BIG)
-    return q, nbr_t, vec_t, beam_id, bd, beam_ck, vis, Bpad, bt, vpad, vbits
+    return (q, nbr_t, vec_t, beam_id, bd, beam_ck.astype(jnp.int32),
+            vis.astype(jnp.int32), Bpad, bt, vpad, vbits)
 
 
 def _apply_tombstone(tombstone, nbr_table, beam_id, beam_d, n: int):
@@ -428,12 +446,69 @@ def _encoding_operands(q, vec_table, vec_scale, vec_codebook):
     return q, _scale_operand(vec_scale, q.shape[1]), dummy_cb, "dense"
 
 
+def _traversal_call(kernel, q, nbr_table, vec_table, beam_id, beam_d,
+                    beam_ck, visited, n: int, *, width: int, b_tile: int,
+                    interpret, vec_scale, vec_codebook, tombstone,
+                    extra_out: jax.ShapeDtypeStruct, **static):
+    """Shared wrapper of the per-hop and the persistent kernel: tombstone
+    masking, padding, encoding operands, the ``pallas_call`` itself and
+    the slicing back.  ``extra_out`` is the kernel's fifth output per row
+    (fresh mask or counters).  Returns the five outputs at the caller's
+    batch size with booleans restored and +inf distances."""
+    Bq = q.shape[0]
+    R = nbr_table.shape[1]
+    ef = beam_id.shape[1]
+    assert n < (1 << 24), "one-hot gather needs fp32-exact node ids"
+    assert vec_table.shape[0] == nbr_table.shape[0]
+    assert width >= 1
+
+    nbr_table, beam_id, beam_d = _apply_tombstone(tombstone, nbr_table,
+                                                  beam_id, beam_d, n)
+    (q, nbr_t, vec_t, beam_id, bd, beam_ck, vis, Bpad, bt, vpad,
+     vbits) = _pad_state(q, nbr_table, vec_table, beam_id, beam_d, beam_ck,
+                         visited, n, b_tile)
+    Npad = nbr_t.shape[0]
+    q, scl, cb, encoding = _encoding_operands(q, vec_t, vec_scale,
+                                              vec_codebook)
+    dq, wv = q.shape[1], vec_t.shape[1]
+    xw = extra_out.shape[1]
+
+    kern = functools.partial(
+        kernel, n=n, R=R, W=width, ef=ef,
+        Wsort=_next_pow2(ef + width * R), hash_bits=vbits,
+        encoding=encoding, **static)
+    row = lambda w: pl.BlockSpec((bt, w), lambda i: (i, 0))
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
+    oid, od, ock, ovis, oxtra = pl.pallas_call(
+        kern,
+        grid=(Bpad // bt,),
+        in_specs=[row(dq), whole((Npad, R)), whole((Npad, wv)),
+                  whole(scl.shape), whole(cb.shape),
+                  row(ef), row(ef), row(ef), row(vpad)],
+        out_specs=(row(ef), row(ef), row(ef), row(vpad), row(xw)),
+        out_shape=(
+            jax.ShapeDtypeStruct((Bpad, ef), jnp.int32),
+            jax.ShapeDtypeStruct((Bpad, ef), jnp.float32),
+            jax.ShapeDtypeStruct((Bpad, ef), jnp.int32),
+            jax.ShapeDtypeStruct((Bpad, vpad), jnp.int32),
+            jax.ShapeDtypeStruct((Bpad, xw), extra_out.dtype),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=resolve_interpret(interpret),
+    )(q, nbr_t, vec_t, scl, cb, beam_id, bd, beam_ck, vis)
+
+    od = jnp.where(od >= BIG, jnp.inf, od)
+    return (oid[:Bq], od[:Bq], ock[:Bq] != 0, ovis[:Bq, :vbits] != 0,
+            oxtra[:Bq])
+
+
 def fused_traversal_hop(q: jax.Array, nbr_table: jax.Array,
                         vec_table: jax.Array, beam_id: jax.Array,
                         beam_d: jax.Array, beam_ck: jax.Array,
                         visited: jax.Array, n: int, *, width: int = 1,
-                        visited_mode: str = "bloom", b_tile: int = 128,
-                        interpret: bool = False,
+                        visited_mode: str = "bloom", b_tile: int = 8,
+                        interpret: Optional[bool] = None,
                         vec_scale: jax.Array = None,
                         vec_codebook: jax.Array = None,
                         tombstone: jax.Array = None
@@ -455,61 +530,14 @@ def fused_traversal_hop(q: jax.Array, nbr_table: jax.Array,
     semantics as ``core.traversal.expansion_round`` minus the counters —
     ``fresh`` (B, W·R) lets the caller account n_dist.
     """
-    Bq, dp = q.shape
-    N1, R = nbr_table.shape
-    ef = beam_id.shape[1]
-    assert n < (1 << 24), "one-hot gather needs fp32-exact node ids"
-    assert vec_table.shape[0] == N1
-    assert width >= 1
-
-    nbr_table, beam_id, beam_d = _apply_tombstone(tombstone, nbr_table,
-                                                  beam_id, beam_d, n)
-    (q, nbr_t, vec_t, beam_id, bd, beam_ck, vis, Bpad, bt, vpad,
-     vbits) = _pad_state(q, nbr_table, vec_table, beam_id, beam_d, beam_ck,
-                         visited, n, b_tile)
-    Npad = nbr_t.shape[0]
-    q, scl, cb, encoding = _encoding_operands(q, vec_t, vec_scale,
-                                              vec_codebook)
-    dq, wv = q.shape[1], vec_t.shape[1]
-
-    kern = functools.partial(
-        _hop_kernel, n=n, R=R, W=width, ef=ef,
-        Wsort=_next_pow2(ef + width * R), hash_bits=vbits,
-        visited_mode=visited_mode, encoding=encoding)
-    out_shapes = (
-        jax.ShapeDtypeStruct((Bpad, ef), jnp.int32),
-        jax.ShapeDtypeStruct((Bpad, ef), jnp.float32),
-        jax.ShapeDtypeStruct((Bpad, ef), bool),
-        jax.ShapeDtypeStruct((Bpad, vpad), bool),
-        jax.ShapeDtypeStruct((Bpad, width * R), bool),
-    )
-    oid, od, ock, ovis, ofresh = pl.pallas_call(
-        kern,
-        grid=(Bpad // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, dq), lambda i: (i, 0)),
-            pl.BlockSpec((Npad, R), lambda i: (0, 0)),
-            pl.BlockSpec((Npad, wv), lambda i: (0, 0)),
-            pl.BlockSpec(scl.shape, lambda i: (0, 0)),
-            pl.BlockSpec(cb.shape, lambda i: (0, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, vpad), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, vpad), lambda i: (i, 0)),
-            pl.BlockSpec((bt, width * R), lambda i: (i, 0)),
-        ),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(q, nbr_t, vec_t, scl, cb, beam_id, bd, beam_ck, vis)
-
-    od = jnp.where(od >= BIG, jnp.inf, od)
-    return (oid[:Bq], od[:Bq], ock[:Bq], ovis[:Bq, :vbits], ofresh[:Bq])
+    R = nbr_table.shape[1]
+    oid, od, ock, ovis, ofresh = _traversal_call(
+        _hop_kernel, q, nbr_table, vec_table, beam_id, beam_d, beam_ck,
+        visited, n, width=width, b_tile=b_tile, interpret=interpret,
+        vec_scale=vec_scale, vec_codebook=vec_codebook, tombstone=tombstone,
+        extra_out=jax.ShapeDtypeStruct((0, width * R), jnp.int32),
+        visited_mode=visited_mode)
+    return oid, od, ock, ovis, ofresh != 0
 
 
 def fused_pilot_search(q: jax.Array, nbr_table: jax.Array,
@@ -517,7 +545,7 @@ def fused_pilot_search(q: jax.Array, nbr_table: jax.Array,
                        beam_d: jax.Array, beam_ck: jax.Array,
                        visited: jax.Array, n: int, *, rounds: int,
                        width: int = 1, visited_mode: str = "bloom",
-                       b_tile: int = 128, interpret: bool = False,
+                       b_tile: int = 8, interpret: Optional[bool] = None,
                        vec_scale: jax.Array = None,
                        vec_codebook: jax.Array = None,
                        tombstone: jax.Array = None
@@ -534,59 +562,12 @@ def fused_pilot_search(q: jax.Array, nbr_table: jax.Array,
     where the three counters are (B,) int32 *deltas* accumulated over the
     executed rounds (the caller adds them to the init-state counters).
     """
-    Bq, dp = q.shape
-    N1, R = nbr_table.shape
-    ef = beam_id.shape[1]
-    assert n < (1 << 24), "one-hot gather needs fp32-exact node ids"
-    assert vec_table.shape[0] == N1
-    assert width >= 1 and rounds >= 0
-
-    nbr_table, beam_id, beam_d = _apply_tombstone(tombstone, nbr_table,
-                                                  beam_id, beam_d, n)
-    (q, nbr_t, vec_t, beam_id, bd, beam_ck, vis, Bpad, bt, vpad,
-     vbits) = _pad_state(q, nbr_table, vec_table, beam_id, beam_d, beam_ck,
-                         visited, n, b_tile)
-    Npad = nbr_t.shape[0]
-    q, scl, cb, encoding = _encoding_operands(q, vec_t, vec_scale,
-                                              vec_codebook)
-    dq, wv = q.shape[1], vec_t.shape[1]
-
-    kern = functools.partial(
-        _persistent_kernel, n=n, R=R, W=width, ef=ef,
-        Wsort=_next_pow2(ef + width * R), hash_bits=vbits,
-        visited_mode=visited_mode, rounds=rounds, encoding=encoding)
-    out_shapes = (
-        jax.ShapeDtypeStruct((Bpad, ef), jnp.int32),
-        jax.ShapeDtypeStruct((Bpad, ef), jnp.float32),
-        jax.ShapeDtypeStruct((Bpad, ef), bool),
-        jax.ShapeDtypeStruct((Bpad, vpad), bool),
-        jax.ShapeDtypeStruct((Bpad, _CNT_LANES), jnp.int32),
-    )
-    oid, od, ock, ovis, ocnt = pl.pallas_call(
-        kern,
-        grid=(Bpad // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, dq), lambda i: (i, 0)),
-            pl.BlockSpec((Npad, R), lambda i: (0, 0)),
-            pl.BlockSpec((Npad, wv), lambda i: (0, 0)),
-            pl.BlockSpec(scl.shape, lambda i: (0, 0)),
-            pl.BlockSpec(cb.shape, lambda i: (0, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, vpad), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, ef), lambda i: (i, 0)),
-            pl.BlockSpec((bt, vpad), lambda i: (i, 0)),
-            pl.BlockSpec((bt, _CNT_LANES), lambda i: (i, 0)),
-        ),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(q, nbr_t, vec_t, scl, cb, beam_id, bd, beam_ck, vis)
-
-    od = jnp.where(od >= BIG, jnp.inf, od)
-    return (oid[:Bq], od[:Bq], ock[:Bq], ovis[:Bq, :vbits],
-            ocnt[:Bq, 0], ocnt[:Bq, 1], ocnt[:Bq, 2])
+    assert rounds >= 0
+    oid, od, ock, ovis, ocnt = _traversal_call(
+        _persistent_kernel, q, nbr_table, vec_table, beam_id, beam_d,
+        beam_ck, visited, n, width=width, b_tile=b_tile,
+        interpret=interpret, vec_scale=vec_scale, vec_codebook=vec_codebook,
+        tombstone=tombstone,
+        extra_out=jax.ShapeDtypeStruct((0, _CNT_LANES), jnp.int32),
+        visited_mode=visited_mode, rounds=rounds)
+    return oid, od, ock, ovis, ocnt[:, 0], ocnt[:, 1], ocnt[:, 2]

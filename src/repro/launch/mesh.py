@@ -10,23 +10,17 @@ from __future__ import annotations
 import jax
 
 
-def _auto_axis_kwargs(n_axes: int) -> dict:
-    """axis_types=Auto where the jax version supports it; older releases
-    (no ``jax.sharding.AxisType``, no ``make_mesh(axis_types=)``) already
-    default to auto sharding-in-types semantics, so omit the kwarg."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for smoke tests / examples on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_auto_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

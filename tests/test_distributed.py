@@ -27,8 +27,8 @@ from repro.core import IndexConfig, PilotANNIndex, SearchParams, \
     brute_force_topk, recall_at_k
 from repro.data import synthetic_vectors
 
-from repro.launch.mesh import _auto_axis_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_auto_axis_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # small real index -> pod arrays
 ds = synthetic_vectors(2048, 16, n_queries=64, seed=0)

@@ -91,13 +91,36 @@ def test_fused_merge_rejects_inexact_id_space():
 
 
 def test_nn_descent_pallas_route_matches_jnp():
-    """The full NN-descent with the Pallas merge (interpret mode) must
-    produce the same candidate lists as the pure-jnp route."""
+    """NN-descent rounds with the Pallas merge (interpret mode) produce
+    the same candidate lists as the pure-jnp route, from random starting
+    lists (the brute-force seed is exact on the CPU, so rounds after it
+    would have nothing to change), and so does the whole nn_descent."""
     rng = np.random.default_rng(7)
     x = rng.normal(size=(300, 12)).astype(np.float32)
-    ids_j, dd_j = device_build.nn_descent(x, 8, rounds=3, seed=1, S=4,
+    n = len(x)
+    ids = np.stack([rng.permutation(np.delete(np.arange(n), i))[:8]
+                    for i in range(n)]).astype(np.int32)
+    dd = ((x[ids] - x[:, None, :]) ** 2).sum(-1).astype(np.float32)
+    o = np.argsort(dd, axis=1)
+    ids, dd = np.take_along_axis(ids, o, 1), np.take_along_axis(dd, o, 1)
+    x_pad = jnp.asarray(np.concatenate([x, np.zeros((1, 12), np.float32)]))
+    xsq = jnp.sum(x_pad * x_pad, axis=-1)
+    routes = {}
+    for pallas in (False, True):
+        li, ld = jnp.asarray(ids), jnp.asarray(dd)
+        used = jnp.zeros(li.shape, bool)
+        for _ in range(3):
+            li, ld, used = device_build._nn_descent_round(
+                x_pad, xsq, li, ld, used, n=n, S=4, block=n,
+                use_pallas=pallas, interpret=True if pallas else None)
+        routes[pallas] = (np.asarray(li), np.asarray(ld), np.asarray(used))
+    (ij, dj, uj), (ip, dp, up) = routes[False], routes[True]
+    assert not np.array_equal(ij, ids)        # the rounds changed lists
+    assert np.array_equal(ij, ip) and np.array_equal(uj, up)
+    assert np.array_equal(dj[ij < n], dp[ip < n])
+    ids_j, dd_j = device_build.nn_descent(x, 8, rounds=3, S=4,
                                           use_pallas=False)
-    ids_p, dd_p = device_build.nn_descent(x, 8, rounds=3, seed=1, S=4,
+    ids_p, dd_p = device_build.nn_descent(x, 8, rounds=3, S=4,
                                           use_pallas=True, interpret=True)
     assert np.array_equal(ids_j, ids_p)
     live = ids_j < len(x)

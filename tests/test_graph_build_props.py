@@ -20,6 +20,7 @@ NN-descent candidate distances must be monotone non-increasing across
 rounds (the merge keeps the best of every duplicate, so each rank can
 only improve)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -77,6 +78,45 @@ from repro.core.device_build import (build_graph_device, nn_descent,
 from repro.core.graph_build import (add_reverse_edges, brute_knn, occludes,
                                     occlusion_prune, patch_reverse_edges,
                                     prune_one)
+
+
+def random_lists(x, K, seed):
+    """Each row's K distinct random other rows, sorted by exact squared
+    distance: NN-descent's classic random start."""
+    n = len(x)
+    rng = np.random.default_rng(seed)
+    ids = np.stack([rng.permutation(np.delete(np.arange(n), i))[:K]
+                    for i in range(n)])
+    d = ((x[ids] - x[:, None, :]) ** 2).sum(-1)
+    o = np.argsort(d, axis=1)
+    return (np.take_along_axis(ids, o, 1).astype(np.int32),
+            np.take_along_axis(d, o, 1).astype(np.float32))
+
+
+def descent_rounds(x, ids, dd, rounds, S, *, flags=True, use_pallas=False):
+    """The lists after each of ``rounds`` NN-descent rounds from (ids, dd).
+    ``flags=False`` clears the sampled flags every round, so each round
+    samples the S nearest entries again."""
+    from repro.core.device_build import _nn_descent_round
+    n, dim = x.shape
+    x_pad = jnp.asarray(np.concatenate([x, np.zeros((1, dim), np.float32)]))
+    xsq = jnp.sum(x_pad * x_pad, axis=-1)
+    ids, dd = jnp.asarray(ids), jnp.asarray(dd)
+    used = jnp.zeros(ids.shape, bool)
+    out = []
+    for _ in range(rounds):
+        ids, dd, used = _nn_descent_round(
+            x_pad, xsq, ids, dd, used if flags else jnp.zeros_like(used),
+            n=n, S=S, block=min(1024, n), use_pallas=use_pallas,
+            interpret=True if use_pallas else None)
+        out.append((np.asarray(ids), np.asarray(dd)))
+    return out
+
+
+def list_recall(ids, gt):
+    return float(np.mean([len(set(a) & set(b))
+                          for a, b in zip(ids.tolist(), gt.tolist())])
+                 ) / gt.shape[1]
 
 
 def _dataset(seed, n=48, d=6, K=16):
@@ -233,7 +273,7 @@ def test_device_builder_graph_invariants(seed, R):
     edges, no duplicate edges within a row."""
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(96, 8)).astype(np.float32)
-    g = build_graph_device(x, R, rounds=4, seed=seed, repair=False)
+    g = build_graph_device(x, R, rounds=4, repair=False)
     n = len(x)
     nb = g.neighbors
     real = nb < n
@@ -246,6 +286,31 @@ def test_device_builder_graph_invariants(seed, R):
         assert len(set(kept.tolist())) == len(kept)
 
 
+@settings(deadline=None, max_examples=4)
+@given(st.integers(0, 10_000), st.sampled_from([4, 6]))
+def test_reverse_candidates_union(seed, R):
+    """reverse_candidates: each row's list is its k-NN candidates plus
+    every row whose pruned list points at it (up to R), deduplicated,
+    sorted by exact distance, sentinel-padded."""
+    from repro.core.device_build import reverse_candidates
+    x, ids, dd = _dataset(seed, K=8)
+    n = len(x)
+    kept = occlusion_prune(x, ids, dd, R, keep_pruned=False)
+    oi, od = reverse_candidates(x, ids, dd, kept)
+    assert oi.shape == (n, 8 + R)
+    for v in range(n):
+        srcs = np.flatnonzero((kept == v).any(axis=1))[:R]
+        want = set(ids[v][ids[v] < n].tolist()) | set(srcs.tolist())
+        live = oi[v][oi[v] < n]
+        assert len(set(live.tolist())) == len(live)
+        assert set(live.tolist()) == want
+        d = ((x[live] - x[v]) ** 2).sum(-1)
+        np.testing.assert_allclose(od[v][:len(live)], d, rtol=1e-5,
+                                   atol=1e-5)
+        assert (np.diff(od[v][:len(live)]) >= 0).all()
+        assert np.isinf(od[v][len(live):]).all()
+
+
 @settings(deadline=None, max_examples=3)
 @given(st.integers(0, 10_000))
 def test_nn_descent_monotone_rounds(seed):
@@ -255,10 +320,28 @@ def test_nn_descent_monotone_rounds(seed):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(128, 8)).astype(np.float32)
     prev = None
-    for r in (1, 2, 3, 4):
-        _, dd = nn_descent(x, 8, rounds=r, seed=seed, S=4)
+    lists = descent_rounds(x, *random_lists(x, 8, seed), 4, 4)
+    for r, (_, dd) in enumerate(lists, 1):
         if prev is not None:
             worse = dd > prev
             assert not worse.any(), \
                 f"round {r}: {int(worse.sum())} ranks got worse"
         prev = dd
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_nn_descent_rounds_raise_list_recall(seed):
+    """From random lists every round finds more of the true 8 nearest,
+    and sampling each node's not-yet-sampled entries first (the "new"
+    flags) ends ahead of sampling its S nearest every round, which
+    re-joins the same neighbourhoods."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(512, 8)).astype(np.float32)
+    gt, _ = brute_knn(x, 8)
+    ids, dd = random_lists(x, 8, seed)
+    flags = [list_recall(i, gt) for i, _ in descent_rounds(x, ids, dd, 6, 4)]
+    nearest = [list_recall(i, gt) for i, _ in
+               descent_rounds(x, ids, dd, 6, 4, flags=False)]
+    recall = [list_recall(ids, gt)] + flags
+    assert all(b > a for a, b in zip(recall, recall[1:])), recall
+    assert flags[-1] > nearest[-1] + 0.03, (flags, nearest)

@@ -76,3 +76,51 @@ def test_fes_distances_padding_safety():
     with pytest.raises(AssertionError):
         fes_distances(jnp.zeros((2, 4, 100)), jnp.zeros((2, 130, 100)),
                       interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# where kernels run and where their compiles are kept
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,asked,expect", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+])
+def test_interpret_follows_backend(monkeypatch, backend, asked, expect):
+    from repro.kernels import backend as KB
+    monkeypatch.setattr(KB.jax, "default_backend", lambda: backend)
+    if expect is ValueError:
+        with pytest.raises(ValueError, match="interpret"):
+            KB.resolve_interpret(asked)
+    else:
+        assert KB.resolve_interpret(asked) is expect
+
+
+def test_fused_expand_merge_refuses_interpreter_on_tpu(monkeypatch):
+    from repro.kernels import backend as KB
+    monkeypatch.setattr(KB.jax, "default_backend", lambda: "tpu")
+    B, R, ef, d, n = 8, 8, 16, 8, 100
+    with pytest.raises(ValueError, match="interpret"):
+        fused_expand_merge(
+            jnp.zeros((B, d)), jnp.zeros((B, R, d)),
+            jnp.zeros((B, R), jnp.int32), jnp.zeros((B, R), bool),
+            jnp.full((B, ef), n, jnp.int32), jnp.zeros((B, ef)),
+            jnp.ones((B, ef), bool), n, interpret=True)
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    from pathlib import Path
+    from repro.runtime import compile_cache as CC
+    old = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(CC.__file__).resolve().parents[3] / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert CC.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -21,8 +21,8 @@ from repro.models.moe import init_moe, moe_ffn
 from repro.models import moe_sharded
 
 cfg = reduced(get_config("olmoe-1b-7b"))  # 4 experts, top-2
-from repro.launch.mesh import _auto_axis_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_auto_axis_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = init_moe(jax.random.PRNGKey(0), cfg)
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(4, 16, cfg.d_model)).astype(np.float32)
